@@ -1,0 +1,4 @@
+"""``decode_mfu`` read in the cells judged on ``output_tokens_per_s``."""
+from harness import spec
+
+compute = spec.metric_function("decode_mfu")

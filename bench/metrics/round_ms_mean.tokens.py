@@ -1,0 +1,4 @@
+"""``round_ms_mean`` read in the cells judged on ``output_tokens_per_s``."""
+from harness import spec
+
+compute = spec.metric_function("round_ms_mean")
