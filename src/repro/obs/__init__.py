@@ -8,6 +8,10 @@ Three pieces, usable separately:
   get one on ``report.trace``; tracing never changes results (traced
   runs are bit-identical to untraced ones) and costs nothing when off —
   the engine hot loops carry no instrumentation either way.
+  :class:`SpanRecorder` is the live counterpart for the served path:
+  attached to an ``Orchestrator`` (``orch.recorder = SpanRecorder()``),
+  it records ``orch.*``, ``engine.*`` and ``req.queue`` spans as they
+  happen, also onto a running ``jax.profiler`` trace.
 * :mod:`repro.obs.metrics` — :class:`Counter` / :class:`Gauge` /
   :class:`LogHistogram` in a :class:`MetricsRegistry`; streaming p50/p99
   in O(buckets) memory, snapshot + diff for run-to-run comparison.
@@ -20,14 +24,14 @@ without jax installed.
 """
 from .metrics import (Counter, Gauge, LogHistogram, MetricsRegistry,
                       MetricsSnapshot)
-from .trace import Epoch, Marker, RunTrace, Span, Tracer
+from .trace import Epoch, Marker, RunTrace, Span, SpanRecorder, Tracer
 from .decode import (decode_orchestrator_trace, decode_sim_trace,
                      merge_region_traces)
 from .export import export_chrome_trace, to_chrome_trace
 
 __all__ = [
     "Counter", "Gauge", "LogHistogram", "MetricsRegistry", "MetricsSnapshot",
-    "Epoch", "Marker", "RunTrace", "Span", "Tracer",
+    "Epoch", "Marker", "RunTrace", "Span", "SpanRecorder", "Tracer",
     "decode_orchestrator_trace", "decode_sim_trace",
     "export_chrome_trace", "merge_region_traces", "to_chrome_trace",
 ]

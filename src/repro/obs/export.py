@@ -23,6 +23,9 @@ _US = 1_000_000.0
 
 
 def _span_event(s: Span) -> Dict[str, Any]:
+    args = dict(s.args)
+    if s.sid is not None:
+        args.update(id=s.sid, parent=s.parent)
     return {
         "name": s.name,
         "cat": s.cat,
@@ -31,7 +34,7 @@ def _span_event(s: Span) -> Dict[str, Any]:
         "dur": (s.t1 - s.t0) * _US,
         "pid": s.pid,
         "tid": s.tid,
-        "args": dict(s.args),
+        "args": args,
     }
 
 

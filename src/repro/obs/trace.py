@@ -16,14 +16,22 @@ the epoch history — which chain composition was active when — via
 ``reconfigure``).  That is what makes tracing structurally zero-cost when
 disabled and bit-neutral when enabled: the hot dispatch loops are
 byte-for-byte the same code either way.
+
+The served path on a device is the exception: what its host does between
+device programs cannot be decoded afterwards.  :class:`SpanRecorder` records
+live spans there, on the host's ``perf_counter_ns`` clock, from the call
+sites of the orchestrator and the chain engines that hold one; with none
+attached each site costs one ``is None`` test.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import time
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
-__all__ = ["Span", "Marker", "Epoch", "RunTrace", "Tracer"]
+__all__ = ["Span", "Marker", "Epoch", "RunTrace", "Tracer", "SpanRecorder"]
 
 #: lane (pid) reserved for run-level markers
 RUN_LANE = 0
@@ -51,6 +59,9 @@ class Span:
     pid: int
     tid: int
     args: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    #: live spans (:class:`SpanRecorder`): this span's id and its parent's
+    sid: Optional[int] = None
+    parent: Optional[int] = None
 
     @property
     def duration(self) -> float:
@@ -199,3 +210,99 @@ class RunTrace:
                     raise AssertionError(
                         f"request {jid}: queue ends at {queue[-1].t1!r} but "
                         f"service starts at {service[-1].t0!r}")
+
+
+class SpanRecorder:
+    """Live spans of the served path, kept in memory.
+
+    :meth:`begin` opens a span inside the innermost open one and returns its
+    id; :meth:`end` closes it, and any span still open inside it, and
+    records a :class:`Span` (``cat`` names the layer, ``t0``/``t1`` are
+    ``perf_counter_ns`` in seconds, ``sid``/``parent`` link it to the span
+    it ran in, ``args`` carry ids and counts).  :meth:`phase` ends the
+    innermost span and opens the next step beside it.
+
+    Each open span is also a ``jax.profiler.TraceAnnotation`` of the same
+    name where jax is installed.  An annotation records nothing unless a
+    profiler trace is running; during one, the span lands on the profiler's
+    host plane, on the device trace's clock.
+
+    ``req.queue`` spans run from a request's submission (:meth:`queued`) to
+    the start of its admission (:meth:`dequeued`); they end after the fact,
+    so they are kept in memory only.
+    """
+
+    def __init__(self) -> None:
+        self.spans: List[Span] = []
+        # stack of open spans: [sid, name, cat, t0_ns, parent, args, annotation]
+        self._open: List[list] = []
+        self._next = 0
+        self._queued: Dict[int, int] = {}     # rid -> submission, ns
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:                   # numpy-only install
+            TraceAnnotation = None
+        self._annotation = TraceAnnotation
+
+    def begin(self, name: str, cat: str, **args: Any) -> int:
+        sid = self._next
+        self._next += 1
+        parent = self._open[-1][0] if self._open else None
+        ann = None
+        if self._annotation is not None:
+            ann = self._annotation(name)
+            ann.__enter__()
+        self._open.append([sid, name, cat, time.perf_counter_ns(), parent,
+                           args, ann])
+        return sid
+
+    def end(self, sid: int, **args: Any) -> None:
+        """Close span ``sid`` (adding ``args``) and every span opened inside
+        it; a span already closed is left as it is."""
+        if not any(o[0] == sid for o in self._open):
+            return
+        t1 = time.perf_counter_ns() * 1e-9
+        while True:
+            o = self._open.pop()
+            if o[6] is not None:
+                o[6].__exit__(None, None, None)
+            if o[0] == sid:
+                o[5].update(args)
+            self.spans.append(Span(o[1], o[2], o[3] * 1e-9, t1, RUN_LANE, 0,
+                                   o[5], sid=o[0], parent=o[4]))
+            if o[0] == sid:
+                return
+
+    def phase(self, name: str, cat: str, **args: Any) -> int:
+        """End the innermost open span and begin ``name`` in its place."""
+        if self._open:
+            self.end(self._open[-1][0])
+        return self.begin(name, cat, **args)
+
+    @contextlib.contextmanager
+    def span(self, name: str, cat: str, **args: Any) -> Iterator[int]:
+        sid = self.begin(name, cat, **args)
+        try:
+            yield sid
+        finally:
+            self.end(sid)
+
+    def queued(self, rid: int) -> None:
+        self._queued[rid] = time.perf_counter_ns()
+
+    def dequeued(self, rid: int) -> None:
+        t0 = self._queued.pop(rid, None)
+        if t0 is None:
+            return
+        sid = self._next
+        self._next += 1
+        self.spans.append(Span("req.queue", "request", t0 * 1e-9,
+                               time.perf_counter_ns() * 1e-9, QUEUE_LANE,
+                               int(rid), {"rid": rid}, sid=sid))
+
+    def run_trace(self) -> "RunTrace":
+        """The spans recorded so far, laid out for :func:`export_chrome_trace`:
+        nested spans on the host lane, queue spans one track per request."""
+        return RunTrace(spans=sorted(self.spans, key=lambda s: (s.t0, -s.t1)),
+                        markers=[], lanes={RUN_LANE: "host", QUEUE_LANE: "queue"},
+                        meta={"clock": "perf_counter_ns"})

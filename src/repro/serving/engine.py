@@ -11,6 +11,14 @@ runs one batched step over the active slots (row count bucketed to a power
 of two), each over the power-of-two span of positions its pages would
 cover (``kv_span``).
 
+An engine keeps counts (``counts``: prefilled and padding positions,
+preemptions, jit-cache clears) and, when its orchestrator holds a
+``repro.obs.SpanRecorder``, records ``engine.admit`` and ``engine.step``
+spans with their phases.  Inside the step programs the page gather, the
+model's decode and the write-back run under the named scopes ``kv_gather``,
+``model_decode`` and ``kv_scatter``, which a device trace shows on each
+operation.
+
 ``PagedChainEngine`` is the continuously-batched variant over a
 ``PagedCache``: admission scatters O(prompt) pages instead of copying the
 whole cache, decode gathers only the active slots into a dense batch
@@ -48,8 +56,17 @@ def _pow2(n: int) -> int:
 PREFILL_BUCKET_LIMIT = 8
 DECODE_SHAPE_LIMIT = 16
 
+#: what an engine counts (``counts``), published as ``engine.<name>``
+COUNTS = ("prefill_tokens", "prefill_pad_tokens", "preemptions",
+          "jit_clears")
+
 
 class ChainEngine:
+    #: a ``repro.obs.SpanRecorder``, handed in by the orchestrator
+    recorder = None
+    #: this engine's index among its orchestrator's engines (span args)
+    engine_id = 0
+
     def __init__(self, model: Model, params, chain: Chain, capacity: int,
                  max_seq: int, page_size: int = PAGE_SIZE):
         self.model = model
@@ -60,10 +77,8 @@ class ChainEngine:
         self.page_size = page_size
         self.slots = SlotCache(model, capacity, max_seq)
         self.requests: Dict[int, Request] = {}      # slot -> request
-        self._prefill_jit = jax.jit(model.prefill)
         self._init_kv_programs(self.slots, jax.jit(
             self._step_impl, static_argnums=(3,), donate_argnums=(1,)))
-        self._prefill_shapes: set = set()
 
     def _init_kv_programs(self, kv, step_jit) -> None:
         # Both KV layouts hand the model's prefill and decode the same
@@ -72,10 +87,14 @@ class ChainEngine:
         # positions each decode step.  On a TPU a row's logits change with
         # the batch's row count and, at small row counts, with its span; so
         # equal shapes are what keeps the layouts' greedy streams identical.
+        self._kv = kv
         self._prefill_buffer = kv.prefill_buffer
+        self._prefill_jit = jax.jit(self.model.prefill)
         self._decode_jit = jax.jit(self.model.decode_step, donate_argnums=(1,))
         self._step_jit = step_jit
+        self._prefill_shapes: set = set()
         self._step_shapes: set = set()
+        self.counts: Dict[str, int] = dict.fromkeys(COUNTS, 0)
 
     # -- jit-cache hygiene -------------------------------------------------------
     @property
@@ -92,6 +111,7 @@ class ChainEngine:
                 and len(self._prefill_shapes) >= PREFILL_BUCKET_LIMIT:
             self._prefill_jit.clear_cache()
             self._prefill_shapes.clear()
+            self.counts["jit_clears"] += 1
         self._prefill_shapes.add(key)
         return self._prefill_jit(self.params, cache_one,
                                  {"tokens": jnp.asarray(padded)})
@@ -102,6 +122,7 @@ class ChainEngine:
                 and len(self._step_shapes) >= DECODE_SHAPE_LIMIT:
             self._step_jit.clear_cache()
             self._step_shapes.clear()
+            self.counts["jit_clears"] += 1
         self._step_shapes.add(key)
 
     # -- admission --------------------------------------------------------------
@@ -135,40 +156,65 @@ class ChainEngine:
             logits, buf = self._decode_jit(self.params, buf, last, lpos)
         return logits, buf
 
+    def _acquire(self, true_len: int) -> Optional[int]:
+        return self.slots.acquire()
+
+    def _release(self, slot: int) -> None:
+        del self.requests[slot]
+        self.slots.release(slot)
+
     def admit(self, req: Request, now: float = 0.0) -> bool:
-        slot = self.slots.acquire()
+        """Prefill ``req`` into a free slot and take its first token; False
+        when no slot (or, paged, no pages for the prompt) is free."""
+        tokens = req.context_tokens
+        true_len = len(tokens)
+        slot = self._acquire(true_len)
         if slot is None:
             return False
-        tokens = req.context_tokens
+        pad = kv_span(true_len, self.page_size, self.max_seq) - true_len
+        self.counts["prefill_tokens"] += true_len
+        self.counts["prefill_pad_tokens"] += pad
+        rec = self.recorder
+        if rec is not None:
+            rec.dequeued(req.rid)
+            top = rec.begin("engine.admit", "engine", engine=self.engine_id,
+                            rid=req.rid, prompt=true_len, pad=pad)
+            rec.begin("engine.prefill", "engine")
         logits, buf = self.prefill(tokens)
-        self.slots.write_prefill(slot, buf, len(tokens))
+        if rec is not None:
+            rec.phase("engine.fetch", "engine")
+        next_tok = int(jnp.argmax(logits[0]))
+        if rec is not None:
+            rec.phase("engine.write", "engine")
+        self._kv.write_prefill(slot, buf, true_len)
+        if rec is not None:
+            rec.end(top)
         req.slot = slot
         req.state = State.RUNNING
         if req.start_time is None:
             req.start_time = now
         self.requests[slot] = req
-        req.output.append(int(jnp.argmax(logits[0])))
+        req.output.append(next_tok)
         if req.done:                                  # e.g. max_new_tokens == 1
             req.state = State.DONE
             req.finish_time = now
-            del self.requests[slot]
-            self.slots.release(slot)
+            self._release(slot)
         return True
 
     # -- decode ----------------------------------------------------------------
     def _step_impl(self, params, cache, rows, span, tokens, lengths):
         # gather the rows' first span positions, decode, write back
-        sub = self.slots.gather(cache, rows, span)
-        logits, sub = self.model.decode_step(params, sub, tokens, lengths)
-        return logits, self.slots.scatter(cache, rows, sub)
+        with jax.named_scope("kv_gather"):
+            sub = self.slots.gather(cache, rows, span)
+        with jax.named_scope("model_decode"):
+            logits, sub = self.model.decode_step(params, sub, tokens, lengths)
+        with jax.named_scope("kv_scatter"):
+            return logits, self.slots.scatter(cache, rows, sub)
 
-    def step(self, now: float = 0.0) -> List[Request]:
-        """One batched decode step over the active slots, shaped as the
-        paged engine shapes it (see ``_init_kv_programs``): row count
-        bucketed to a power of two, positions cut to the ``kv_span`` its
-        pages would cover.  Returns the requests that completed."""
-        if not self.requests:
-            return []
+    def _plan(self):
+        """The active slots, and the step's batch: row count bucketed to a
+        power of two, positions cut to the ``kv_span`` the rows' pages
+        would cover (the paged engine's shapes, see ``_init_kv_programs``)."""
         active = sorted(self.requests)
         n = len(active)
         rows = np.asarray(active + [active[0]] * (_pow2(n) - n), np.int32)
@@ -178,22 +224,51 @@ class ChainEngine:
         # this step writes the pending token there and advances it.
         lengths = self.slots.lengths[rows]
         span = kv_span(int(lengths.max()) + 1, self.page_size, self.max_seq)
+        return active, (rows, span, tokens, lengths)
+
+    def _launch(self, batch):
+        rows, span, tokens, lengths = batch
         self._step_cache_guard((rows.shape, span))
         logits, self.slots.cache = self._step_jit(
             self.params, self.slots.cache, jnp.asarray(rows), span,
             jnp.asarray(tokens), jnp.asarray(lengths))
+        return logits
+
+    def step(self, now: float = 0.0) -> List[Request]:
+        """One batched decode step over the active slots; returns the
+        requests that completed."""
+        if not self.requests:
+            return []
+        rec = self.recorder
+        if rec is not None:
+            top = rec.begin("engine.step", "engine", engine=self.engine_id)
+            rec.begin("engine.plan", "engine")
+        active, batch = self._plan()
+        n = len(active)
+        if not active:
+            if rec is not None:
+                rec.end(top, rows=0, pad=0)
+            return []
+        if rec is not None:
+            rec.phase("engine.launch", "engine")
+        logits = self._launch(batch)
+        if rec is not None:
+            rec.phase("engine.fetch", "engine")
         next_tokens = np.asarray(jnp.argmax(logits[:n], axis=-1))
+        if rec is not None:
+            rec.phase("engine.commit", "engine")
         finished = []
         for i, slot in enumerate(active):
-            self.slots.lengths[slot] += 1
+            self._kv.lengths[slot] += 1
             req = self.requests[slot]
             req.output.append(int(next_tokens[i]))
             if req.done:
                 req.state = State.DONE
                 req.finish_time = now
                 finished.append(req)
-                del self.requests[slot]
-                self.slots.release(slot)
+                self._release(slot)
+        if rec is not None:
+            rec.end(top, rows=n, pad=_pow2(n) - n)
         return finished
 
     # -- failover ----------------------------------------------------------------
@@ -252,10 +327,8 @@ class PagedChainEngine(ChainEngine):
         self.preempted: List[Request] = []
         self._admit_seq: Dict[int, int] = {}        # slot -> admission counter
         self._seq = 0
-        self._prefill_jit = jax.jit(model.prefill)
         self._init_kv_programs(self.cache, jax.jit(self._step_impl,
                                                    donate_argnums=(1,)))
-        self._prefill_shapes: set = set()
 
     # -- admission --------------------------------------------------------------
     @property
@@ -270,28 +343,16 @@ class PagedChainEngine(ChainEngine):
     def free_pages(self) -> int:
         return self.cache.free_pages
 
-    def admit(self, req: Request, now: float = 0.0) -> bool:
-        tokens = req.context_tokens
-        true_len = len(tokens)
+    @property
+    def pages_in_use(self) -> int:
+        return self.cache.total_pages - self.cache.free_pages
+
+    def _acquire(self, true_len: int) -> Optional[int]:
         slot = self.cache.acquire(true_len)
-        if slot is None:
-            return False                 # no slot, or page budget exhausted
-        logits, buf = self.prefill(tokens)
-        next_tok = int(jnp.argmax(logits[0]))
-        self.cache.write_prefill(slot, buf, true_len)
-        req.slot = slot
-        req.state = State.RUNNING
-        if req.start_time is None:
-            req.start_time = now
-        self.requests[slot] = req
-        self._admit_seq[slot] = self._seq
-        self._seq += 1
-        req.output.append(next_tok)
-        if req.done:
-            req.state = State.DONE
-            req.finish_time = now
-            self._release(slot)
-        return True
+        if slot is not None:             # else no slot, or no pages left
+            self._admit_seq[slot] = self._seq
+            self._seq += 1
+        return slot
 
     def _release(self, slot: int) -> None:
         self.requests.pop(slot, None)
@@ -306,6 +367,7 @@ class PagedChainEngine(ChainEngine):
         req.retries += 1
         self.preempted.append(req)
         self._release(slot)
+        self.counts["preemptions"] += 1
 
     def take_preempted(self) -> List[Request]:
         """Drain requests preempted by page exhaustion (orchestrator
@@ -317,26 +379,19 @@ class PagedChainEngine(ChainEngine):
     def _step_impl(self, params, leaves, page_ids, slot_idx, tokens, lengths,
                    write_page, write_off):
         # gather the rows' pages, decode, write back one position per row
-        cache = self.cache.gather(leaves, page_ids, slot_idx)
-        logits, cache = self.model.decode_step(params, cache, tokens, lengths)
-        return logits, self.cache.scatter(leaves, cache, slot_idx, lengths,
-                                          write_page, write_off)
+        with jax.named_scope("kv_gather"):
+            cache = self.cache.gather(leaves, page_ids, slot_idx)
+        with jax.named_scope("model_decode"):
+            logits, cache = self.model.decode_step(params, cache, tokens,
+                                                   lengths)
+        with jax.named_scope("kv_scatter"):
+            return logits, self.cache.scatter(leaves, cache, slot_idx,
+                                              lengths, write_page, write_off)
 
-    def _step(self, view):
-        self._step_cache_guard((view["page_ids"].shape,
-                                view["slot_idx"].shape))
-        logits, self.cache.leaves = self._step_jit(
-            self.params, self.cache.leaves, *(jnp.asarray(view[k]) for k in (
-                "page_ids", "slot_idx", "tokens", "lengths", "write_page",
-                "write_off")))
-        return logits
-
-    def step(self, now: float = 0.0) -> List[Request]:
-        """One continuously-batched decode round; returns completions."""
-        if not self.requests:
-            return []
-        # Guarantee a write page for every active row, preempting the
-        # youngest request when the pool runs dry (its pages free the rest).
+    def _plan(self):
+        """Guarantee a write page for every active row, preempting the
+        youngest request when the pool runs dry (its pages free the rest);
+        then the surviving slots and their dense batch (``decode_view``)."""
         alive = sorted(self.requests, key=lambda s: self._admit_seq[s])
         for slot in list(alive):
             if slot not in alive:
@@ -344,7 +399,7 @@ class PagedChainEngine(ChainEngine):
             while slot in alive and not self.cache.ensure_decode_write(slot):
                 self._preempt(alive.pop())
         if not alive:
-            return []
+            return [], None
         active = sorted(alive)
         n = len(active)
         nb = _pow2(n)
@@ -354,19 +409,16 @@ class PagedChainEngine(ChainEngine):
             tokens[i] = self.requests[slot].output[-1]
         tokens[n:] = tokens[0]                     # pad rows mirror row 0
         view["tokens"] = tokens
-        logits = self._step(view)
-        next_tokens = np.asarray(jnp.argmax(logits[:n], axis=-1))
-        finished = []
-        for i, slot in enumerate(active):
-            self.cache.lengths[slot] += 1
-            req = self.requests[slot]
-            req.output.append(int(next_tokens[i]))
-            if req.done:
-                req.state = State.DONE
-                req.finish_time = now
-                finished.append(req)
-                self._release(slot)
-        return finished
+        return active, view
+
+    def _launch(self, view):
+        self._step_cache_guard((view["page_ids"].shape,
+                                view["slot_idx"].shape))
+        logits, self.cache.leaves = self._step_jit(
+            self.params, self.cache.leaves, *(jnp.asarray(view[k]) for k in (
+                "page_ids", "slot_idx", "tokens", "lengths", "write_page",
+                "write_off")))
+        return logits
 
     # -- failover ----------------------------------------------------------------
     def evict_all(self) -> List[Request]:

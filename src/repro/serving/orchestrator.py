@@ -26,6 +26,13 @@ plane is imported lazily; ``OrchestratorConfig.engine_factory`` swaps in a
 numpy-only mock (``repro.serving.mock.MockEngine``) for control-plane tests
 and benchmarks in minimal environments.
 
+Observability: ``metrics`` (a :class:`repro.obs.MetricsRegistry`) receives
+``orch.*`` and ``engine.*`` instruments once per round; ``recorder`` (a
+:class:`repro.obs.SpanRecorder`) records ``orch.round``, ``orch.dispatch``
+and ``req.queue`` spans here and is handed to every engine composed, which
+records its ``engine.*`` spans.  With neither attached, each site is one
+``is None`` test.
+
 Multi-tenant SLO classes: requests carry a class index into
 ``OrchestratorConfig.classes`` (:class:`repro.core.RequestClass`).  The
 central queue is ordered by aged class priority (tier + aging * arrival —
@@ -153,7 +160,36 @@ class Orchestrator:
         # optional repro.obs.MetricsRegistry; publication happens at round
         # granularity in step(), never inside the engines' decode loops
         self.metrics = None
+        self._recorder = None
+        # what the engines count (engine.COUNTS), summed over every engine
+        # this orchestrator composed, retired ones included
+        self._engine_counts: Dict[str, int] = {}
         self._compose()
+
+    @property
+    def recorder(self):
+        """Optional ``repro.obs.SpanRecorder``; setting it hands it to the
+        engines (and to every engine composed later)."""
+        return self._recorder
+
+    @recorder.setter
+    def recorder(self, rec) -> None:
+        self._recorder = rec
+        self._bind_engines()
+
+    def _bind_engines(self) -> None:
+        """Hand each engine that records (the jax chain engines, which keep
+        ``counts``) the recorder, its index and the shared counts."""
+        for k, eng in enumerate(self.engines):
+            if not hasattr(eng, "counts"):
+                continue
+            eng.recorder = self._recorder
+            eng.engine_id = k
+            if eng.counts is not self._engine_counts:
+                for name, n in eng.counts.items():
+                    self._engine_counts[name] = \
+                        self._engine_counts.get(name, 0) + n
+                eng.counts = self._engine_counts
 
     # -- composition (offline time scale) ---------------------------------------
     def _engine_factory(self) -> Callable:
@@ -190,6 +226,7 @@ class Orchestrator:
             for chain, cap in pairs
         ]
         self.recompositions += 1
+        self._bind_engines()
 
     # -- dispatch (online time scale; Alg. 3) -------------------------------------
     def set_admission_level(self, level: float) -> None:
@@ -212,6 +249,8 @@ class Orchestrator:
         return est > rc.deadline * self.admission_level
 
     def submit(self, req: Request, now: float = 0.0) -> None:
+        if self._recorder is not None:
+            self._recorder.queued(req.rid)
         for hook in self.submit_hooks:
             hook(req, now)
         if self._dispatch(req, now):
@@ -228,6 +267,8 @@ class Orchestrator:
         (counting it as one would feed phantom load into the autoscaler's
         rate estimate right when the cluster is already recomposing), and
         work already admitted is never shed."""
+        if self._recorder is not None:
+            self._recorder.queued(req.rid)
         if not self._dispatch(req, now):
             self.queue.push(req)
 
@@ -249,6 +290,13 @@ class Orchestrator:
             break
 
     def _dispatch(self, req: Request, now: float) -> bool:
+        rec = self._recorder
+        if rec is None:
+            return self._jffc(req, now)
+        with rec.span("orch.dispatch", "orch", rid=req.rid):
+            return self._jffc(req, now)
+
+    def _jffc(self, req: Request, now: float) -> bool:
         # engines are sorted fastest-first; JFFC = first with a free slot.
         for idx, eng in enumerate(self.engines):
             if eng.has_free_slot:
@@ -262,6 +310,13 @@ class Orchestrator:
 
     def step(self, now: float = 0.0) -> List[Request]:
         """One decode round across all engines + queue pulls (Alg. 3 line 6)."""
+        rec = self._recorder
+        if rec is None:
+            return self._round(now)
+        with rec.span("orch.round", "orch"):
+            return self._round(now)
+
+    def _round(self, now: float) -> List[Request]:
         self._expire_warming(now)
         done: List[Request] = []
         for eng in self.engines:
@@ -312,13 +367,15 @@ class Orchestrator:
         return done
 
     def _publish_engine_gauges(self) -> None:
-        """Data-plane gauges, round-granularity only (the PR 7 zero-hot-loop
-        contract): active slots, free pages across paged engines, per-engine
-        batch occupancy, live prefill-jit specializations.  Called from
-        :meth:`step` *and* from every eviction / preemption / recomposition
-        path — a page freed by ``evict_all`` must show up in
-        ``orch.free_pages`` without waiting for the next decode round, or
-        traces read as phantom page leaks."""
+        """Data-plane instruments, round-granularity only (no publication
+        inside the engines' loops): active slots, free pages and pages in
+        use across paged engines, per-engine batch occupancy, and the
+        engines' counts as ``engine.*`` counters (prefilled and padding
+        positions, preemptions, jit-cache clears; a clear means the next
+        steps rebuild their programs).  Called from :meth:`step` *and* from
+        every eviction / preemption / recomposition path — a page freed by
+        ``evict_all`` must show up in ``orch.free_pages`` without waiting
+        for the next decode round, or traces read as phantom page leaks."""
         if self.metrics is None:
             return
         m = self.metrics
@@ -328,9 +385,13 @@ class Orchestrator:
                  if hasattr(e, "free_pages")]
         if pages:
             m.gauge("orch.free_pages").set(sum(pages))
-        m.gauge("orch.prefill_buckets").set(
-            sum(getattr(e, "prefill_bucket_count", 0)
-                for e in self.engines))
+        used = [e.pages_in_use for e in self.engines
+                if hasattr(e, "pages_in_use")]
+        if used:
+            m.gauge("engine.pages_in_use").set(sum(used))
+        for name, total in self._engine_counts.items():
+            c = m.counter(f"engine.{name}")
+            c.inc(total - c.value)
         occ = m.histogram("orch.batch_occupancy")
         for e in self.engines:
             if e.capacity:
@@ -462,6 +523,7 @@ class Orchestrator:
             else:
                 evicted.extend(leftover.evict_all())
         self.engines = new_engines
+        self._bind_engines()
         for req in evicted:
             self._resubmit(req, now)
         self._publish_engine_gauges()
